@@ -113,7 +113,7 @@ def backend_sweep(n_list=BIG_N, n_steps: int = 200,
     interp = lockstep_ops.resolve_interpret(None)
     for n_experts in n_list:
         pool = profiles.make_pool(n_experts)
-        block_n = lockstep_ops.default_block_n(n_experts, interp)
+        block_n = lockstep_ops.default_block_n(n_experts)
         kflags = f";interpret={int(interp)};block_n={block_n}"
         secs = {}
         for backend in backends:

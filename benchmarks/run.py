@@ -88,6 +88,8 @@ def main() -> None:
                         "BENCH_<suite>.json baseline is missing instead of "
                         "skipping the gate")
     args = p.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.configure()
     only = set(args.only.split(",")) if args.only else None
     steps = 1200 if args.quick else 4000
     steps_s = 800 if args.quick else 3000

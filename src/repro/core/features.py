@@ -59,6 +59,30 @@ EXP_FEATS = 9
  REQ_RETRY) = range(7)
 
 
+def fixed_order_sum(x: jax.Array, axis: int = -1) -> jax.Array:
+    """Sum over ``axis`` with the float additions in one fixed order.
+
+    A reduce leaves its accumulation order to the compiler, which picks it
+    per program: a batch-1 and a batch-n vmap of the same observation, or
+    the same SAC update inside and outside ``shard_map``, round
+    differently, and the data-axis collect's bit-identity (``training``
+    module docstring) breaks.  Halving the zero-padded axis with
+    elementwise adds pins one pairwise order in every program."""
+    x = jnp.moveaxis(x, axis, -1)
+    n = x.shape[-1]
+    width = 1 << max(n - 1, 0).bit_length()
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - n)])
+    while x.shape[-1] > 1:
+        h = x.shape[-1] // 2
+        x = x[..., :h] + x[..., h:]
+    return x[..., 0]
+
+
+def fixed_order_mean(x: jax.Array) -> jax.Array:
+    """Mean over the last axis in :func:`fixed_order_sum`'s order."""
+    return fixed_order_sum(x) / x.shape[-1]
+
+
 def build_obs(cfg, pool, state: dict, *, fmt: str = "padded") -> dict:
     """Returns the heterogeneous-graph observation in the given layout."""
     if fmt not in ("padded", "segments"):
@@ -163,8 +187,8 @@ def build_obs(cfg, pool, state: dict, *, fmt: str = "padded") -> dict:
     # --- arrived request node (REQ_FEATS,) ---
     arr_f = jnp.stack([
         r["p_len"].astype(jnp.float32) / mp,
-        jnp.mean(r["pred_s"]),
-        jnp.mean(r["pred_d"]) / mo,
+        fixed_order_mean(r["pred_s"]),
+        fixed_order_mean(r["pred_d"]) / mo,
         jnp.zeros(()),
         jnp.zeros(()),
         jnp.zeros(()),

@@ -13,7 +13,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import han as han_lib
+from repro.core import features, han as han_lib
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,9 +51,27 @@ def _mlp_init(key, dims):
     return params
 
 
+@jax.custom_vjp
+def _add_bias(x, b):
+    return x + b
+
+
+def _add_bias_fwd(x, b):
+    return x + b, None
+
+
+def _add_bias_bwd(_, g):
+    # the bias gradient sums over the batch: fixed order, so the sharded
+    # and the single-device training iterations round it identically
+    return g, features.fixed_order_sum(g.reshape(-1, g.shape[-1]), axis=0)
+
+
+_add_bias.defvjp(_add_bias_fwd, _add_bias_bwd)
+
+
 def _mlp(params, x):
     for i, lyr in enumerate(params):
-        x = x @ lyr["w"] + lyr["b"]
+        x = _add_bias(x @ lyr["w"], lyr["b"])
         if i + 1 < len(params):
             x = jax.nn.relu(x)
     return x

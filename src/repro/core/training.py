@@ -249,7 +249,6 @@ def make_iteration(env_cfg: env_lib.EnvConfig, sac_cfg: sac_lib.SACConfig,
     # --- sharded path: the whole iteration under one shard_map ---
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.distributed import sharding
 
     if env_cfg.engine_backend == "shard_map":
@@ -295,7 +294,7 @@ def make_iteration(env_cfg: env_lib.EnvConfig, sac_cfg: sac_lib.SACConfig,
 
     rep = P()
     env_spec = P(dax) if dax is not None else rep
-    sharded = compat.shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(rep, rep, env_spec, buf_specs, rep, rep),
         out_specs=(rep, rep, env_spec, buf_specs, rep, rep),

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 
 def _quantize_int8(x: jax.Array, block: int = 256):
     """Per-block symmetric int8 quantization. x: 1-D fp32."""
@@ -68,7 +66,7 @@ def compressed_allreduce(tree, mesh, axis: str = "data", *,
         avg = ((q_sum.astype(jnp.float32) * scale).reshape(-1)[:n]) / n_dev
         return avg, new_r
 
-    fn = compat.shard_map(local_fn2, mesh=mesh,
+    fn = jax.shard_map(local_fn2, mesh=mesh,
                        in_specs=(P(), P()), out_specs=(P(), P()),
                        check_vma=False)
     avg, new_res = fn(flat, res)
@@ -118,7 +116,7 @@ def ring_allreduce(x: jax.Array, mesh, axis: str = "data") -> jax.Array:
             out = out.at[tgt].set(recv_block)
         return out.reshape(-1)
 
-    fn = compat.shard_map(local_fn, mesh=mesh, in_specs=P(axis, None),
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=P(axis, None),
                        out_specs=P(), check_vma=False)
     out = fn(xp)
     return out[:m]

@@ -66,11 +66,13 @@ def best_mesh_after_failure(n_devices: int, model_parallel: int,
     if data < 1:
         raise ValueError(
             f"cannot keep model={model_parallel} with {n_devices} devices")
-    from repro.launch.mesh import make_mesh_compat
+    auto = jax.sharding.AxisType.Auto
     if want_pod_axis and data % 2 == 0:
-        return make_mesh_compat(
-            (2, data // 2, model_parallel), ("pod", "data", "model"))
-    return make_mesh_compat((data, model_parallel), ("data", "model"))
+        return jax.make_mesh(
+            (2, data // 2, model_parallel), ("pod", "data", "model"),
+            axis_types=(auto,) * 3)
+    return jax.make_mesh((data, model_parallel), ("data", "model"),
+                         axis_types=(auto,) * 2)
 
 
 def reshard_state(state, new_mesh, *, train: bool = True):

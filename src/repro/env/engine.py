@@ -168,7 +168,11 @@ from repro.env.engine_layout import (  # noqa: F401  (re-exported layout API)
 )
 from repro.env.profiles import ExpertPool
 
-INF = jnp.float32(1e30)
+# a python float, not a jax array: a module-level array becomes a hidden
+# constant argument of every program that uses it, and calling an
+# ahead-of-time compiled program (``jit(f).lower(...).compile()``) can then
+# fail with "compiled for N inputs but called with N-1"
+INF = 1e30
 
 BACKENDS = ("xla", "pallas", "shard_map")
 ADMIT_ORDERS = ("fifo", "qos", "qos_aged", "edf")
@@ -232,21 +236,46 @@ def pool_params(pool: ExpertPool, run_caps=None, wait_caps=None,
     return params
 
 
-def admit_sort_key(wait_f: jax.Array, admit_order: str,
-                   latency_L: float = 0.0) -> jax.Array:
-    """The loop-invariant (N, W) key an admission MINIMIZES over live
-    waiters (shared by the XLA body and the Pallas kernel so the backends
-    stay bit-identical).  ``latency_L`` only matters for ``"edf"``."""
+def admit_key(t_arrive: jax.Array, pred_s: jax.Array, pred_d: jax.Array,
+              admit_order: str, latency_L: float = 0.0) -> jax.Array:
+    """The loop-invariant key an admission MINIMIZES over live waiters,
+    from the waiters' ``t_arrive``/``pred_s``/``pred_d`` fields (the
+    Pallas kernel reads them as separate channel planes).  ``latency_L``
+    only matters for ``"edf"``."""
     if admit_order == "fifo":
-        return wait_f[..., WF_T_ARRIVE]
+        return t_arrive
     if admit_order == "qos":
-        return -wait_f[..., WF_PRED_S]
+        return -pred_s
     if admit_order == "edf":
         # earliest (predicted) deadline t_arrive + L * pred_d first
-        return wait_f[..., WF_T_ARRIVE] + latency_L * wait_f[..., WF_PRED_D]
+        return t_arrive + latency_L * pred_d
     # qos_aged: argmax over waiters of pred_s + beta*(clock - t_arrive) ==
     # argmin of beta*t_arrive - pred_s (clock is common per expert).
-    return QOS_AGE_BETA * wait_f[..., WF_T_ARRIVE] - wait_f[..., WF_PRED_S]
+    return QOS_AGE_BETA * t_arrive - pred_s
+
+
+def admit_sort_key(wait_f: jax.Array, admit_order: str,
+                   latency_L: float = 0.0) -> jax.Array:
+    """:func:`admit_key` of a packed (..., WAIT_F_CH) float tensor (the
+    XLA body and the failover retry buffer; the Pallas kernel calls
+    :func:`admit_key` on its channel planes), so every admission ranks
+    waiters identically."""
+    return admit_key(wait_f[..., WF_T_ARRIVE], wait_f[..., WF_PRED_S],
+                     wait_f[..., WF_PRED_D], admit_order, latency_L)
+
+
+def slot_sum(x: jax.Array, *, keepdims: bool = False) -> jax.Array:
+    """Sum over the slot (last) axis, adding slots left to right.
+
+    A float reduce leaves its accumulation order to the compiler, and the
+    XLA backend and the Pallas kernel are compiled by different ones; the
+    completion accumulators are float sums over up to R finishing slots,
+    so both backends add them in this one explicit order to stay
+    bit-identical on every platform."""
+    total = x[..., 0:1]
+    for j in range(1, x.shape[-1]):
+        total = total + x[..., j:j + 1]
+    return total if keepdims else total[..., 0]
 
 
 def advance_shard(params: dict, latency_L: float, queues: dict,
@@ -343,13 +372,13 @@ def advance_shard(params: dict, latency_L: float, queues: dict,
         fin = finished.astype(jnp.float32)
         score = run_f[..., RF_SCORE]
         acc = {
-            "phi": acc["phi"] + jnp.sum(fin * (score * ok), -1),
-            "lat": acc["lat"] + jnp.sum(fin * lat, -1),
-            "score": acc["score"] + jnp.sum(fin * score, -1),
-            "done": acc["done"] + jnp.sum(fin, -1),
-            "viol": acc["viol"] + jnp.sum(fin * (1.0 - ok), -1),
-            "wait": acc["wait"] + jnp.sum(
-                fin * (run_f[..., RF_T_ADMIT] - run_f[..., RF_T_ARRIVE]), -1),
+            "phi": acc["phi"] + slot_sum(fin * (score * ok)),
+            "lat": acc["lat"] + slot_sum(fin * lat),
+            "score": acc["score"] + slot_sum(fin * score),
+            "done": acc["done"] + slot_sum(fin),
+            "viol": acc["viol"] + slot_sum(fin * (1.0 - ok)),
+            "wait": acc["wait"] + slot_sum(
+                fin * (run_f[..., RF_T_ADMIT] - run_f[..., RF_T_ARRIVE])),
         }
         valid_after = validb & ~finished
 
@@ -402,7 +431,6 @@ def _advance_shard_map(params: dict, latency_L: float, queues: dict,
     and debugging, not semantics."""
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.distributed import sharding
 
     axis = sharding.EXPERT
@@ -430,7 +458,7 @@ def _advance_shard_map(params: dict, latency_L: float, queues: dict,
             lambda x: jax.lax.all_gather(x, axis, tiled=True), acc)
         return q, c, acc
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree.map(e_spec, params), jax.tree.map(e_spec, queues),
                   e_spec(clocks), P()),

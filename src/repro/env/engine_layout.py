@@ -88,28 +88,27 @@ PAR_CH = 8
 PAR_CAP_FREE = float(2 ** 24)
 
 
-def fold_channels(x: jax.Array) -> jax.Array:
-    """(N, S, CH) -> (N, S*CH): merge the slot and channel dims row-major.
+def channel_major(x: jax.Array) -> jax.Array:
+    """(..., N, S, CH) -> (..., CH, N, S): one (N, S) plane per channel.
 
-    The folded form is the lockstep kernel's operand layout: a queue
-    tensor's natural trailing dim is CH (4 or 5), which on TPU occupies
-    one 128-wide vector lane register per slot at <5% utilisation — the
-    f32 minimum tile is (8 sublanes, 128 lanes) and the last dim always
-    maps to lanes.  Folding to (N, S*CH) widens the trailing dim so
-    blocks tile the lane axis densely.  Being a row-major reshape it is a
-    pure metadata change (bit-identical, zero-copy under XLA); channel c
-    of slot s lives at column ``s * CH + c``, and only the kernel's
-    entry/exit reshapes ever see the folded form — everything else keeps
-    the 3-D accessors above.
+    The channel-major form is the lockstep kernel's operand layout: the
+    kernel reads channel ``c`` of a block as ``ref[c]``, a (block_n, S)
+    plane with experts on sublanes and slots on lanes, so its body never
+    reshapes a vector.  Mosaic cannot lower a reshape that splits the lane
+    dim (``(B, S*CH) -> (B, S, CH)`` is refused as an unsupported shape
+    cast), and a (B, S, CH) block would put the 4-5 channels on lanes and
+    index them one lane at a time.  Being an axis permutation the
+    transform is bit-identical; only the kernel's call boundary
+    (``ops.lockstep_advance``) sees it — everything else keeps the
+    (N, S, CH) accessors above.  Leading batch dims (a vmapped env) pass
+    through.
     """
-    n, s, ch = x.shape
-    return jnp.reshape(x, (n, s * ch))
+    return jnp.moveaxis(x, -1, -3)
 
 
-def unfold_channels(x: jax.Array, ch: int) -> jax.Array:
-    """Inverse of :func:`fold_channels`: (N, S*CH) -> (N, S, CH)."""
-    n, sc = x.shape
-    return jnp.reshape(x, (n, sc // ch, ch))
+def channel_minor(x: jax.Array) -> jax.Array:
+    """Inverse of :func:`channel_major`: (..., CH, N, S) -> (..., N, S, CH)."""
+    return jnp.moveaxis(x, -3, -1)
 
 
 def empty_queues(n: int, r: int, w: int) -> dict:

@@ -8,10 +8,11 @@ to a multiple of ``block_n`` with inert experts (no work, zero params —
 including zero run/wait capacity) that the lockstep loop never touches;
 their rows are dropped before returning.
 
-The kernel consumes the queues lane-FOLDED (``engine_layout.
-fold_channels``: (N, S, CH) -> (N, S*CH)); the fold/unfold happens here
-at the call boundary as pure row-major reshapes, so callers keep the 3-D
-packed layout and the retile is invisible outside this module.
+The kernel consumes the queues CHANNEL-MAJOR (``engine_layout.
+channel_major``: (N, S, CH) -> (CH, N, S)); the permutation and its
+inverse happen here at the call boundary, so callers keep the packed
+(N, S, CH) layout and the kernel layout is invisible outside this
+module.
 
 ``params`` normally carries the prebuilt (N, PAR_CH) float32 parameter
 pack under ``"par"`` (``engine.pool_params`` stacks it once per window —
@@ -25,11 +26,8 @@ sentinel (every slot live) / all-up / no floor (-INF).  Padded inert
 experts get a zero admit_min, which is harmless: they own zero capacity
 and no waiters.
 
-``block_n=None`` auto-tunes the expert block per backend
-(``default_block_n``): interpret mode wants small blocks (the
-"kernel" is plain traced XLA, so blocks only bound while-loop trip
-counts), real TPU wants blocks big enough to fill the 8x128 f32 tile
-grid from VMEM.
+``block_n=None`` picks the expert block
+(``default_block_n``).
 """
 from __future__ import annotations
 
@@ -40,8 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.env.engine_layout import (
-    PAR_CAP_FREE, RUN_I_CH, RUN_F_CH, WI_VALID,
-    fold_channels, unfold_channels,
+    PAR_CAP_FREE, WI_VALID, channel_major, channel_minor,
 )
 from repro.kernels.lockstep_advance.kernel import lockstep_advance_call
 
@@ -59,18 +56,21 @@ def resolve_interpret(interpret: Optional[bool] = None) -> bool:
     return jax.default_backend() != "tpu"
 
 
-def default_block_n(n: int, interpret: bool) -> int:
-    """Per-backend ``block_n`` auto-tune (used when callers pass ``None``).
+def default_block_n(n: int) -> int:
+    """The expert block used when callers pass ``block_n=None``.
 
-    Interpret mode lowers the pallas_call to plain traced XLA, so the
-    block size only bounds per-block while-loop trip counts — 128 keeps
-    the historical behaviour (and the committed CPU baselines).  On a
-    real TPU each grid step should cover many (8, 128) f32 tiles of the
-    folded operands to amortise grid overhead, so blocks grow to 512
-    experts (= 64 sublane groups) before spilling VMEM at the packed
-    widths used here.
+    In interpret mode the block only bounds per-block while-loop trip
+    counts.  On a TPU the bound is VMEM.  Every (block_n, S) channel
+    plane pads its S = 4..5 lanes to a 128-lane tile, i.e. 512 bytes per
+    expert row, and a grid step holds 33 such planes (20 in, 13 out, each
+    double-buffered by the pipeline) plus the loop's carried planes and
+    temporaries.  The v5e compiler sizes the kernel's scoped VMEM at
+    16.5 MiB for block_n = 256 at R = W = 5, over its 16 MiB default
+    limit, so the block is 128: a multiple of the 8-row sublane tile, so
+    blocks of a padded fleet stay aligned.  ``tests/test_tpu_compile.py``
+    compiles it for v5e at N in {6, 1024, 4096}.
     """
-    return min(n, 128 if interpret else 512)
+    return min(n, 128)
 
 
 @functools.partial(jax.jit, static_argnames=("latency_L", "admit_order",
@@ -94,7 +94,7 @@ def lockstep_advance(params: dict, queues: dict, clocks: jax.Array,
 
     n = clocks.shape[0]
     if block_n is None:
-        block_n = default_block_n(n, interpret)
+        block_n = default_block_n(n)
     bn = min(block_n, n)
     pad = (-n) % bn
     par = params.get("par")
@@ -117,13 +117,14 @@ def lockstep_advance(params: dict, queues: dict, clocks: jax.Array,
                          admit_min.astype(jnp.float32)],
                         axis=-1)
     par = par.astype(jnp.float32)
-    run_i = fold_channels(queues["run_i"])
-    run_f = fold_channels(queues["run_f"])
-    wait_i = fold_channels(queues["wait_i"])
-    wait_f = fold_channels(queues["wait_f"])
+    run_i = channel_major(queues["run_i"])
+    run_f = channel_major(queues["run_f"])
+    wait_i = channel_major(queues["wait_i"])
+    wait_f = channel_major(queues["wait_f"])
     clk = clocks[:, None].astype(jnp.float32)
     if pad:
-        grow = lambda x: jnp.pad(x, ((0, pad), (0, 0)))
+        grow = lambda x: jnp.pad(x, [(0, 0)] * (x.ndim - 2)
+                                 + [(0, pad), (0, 0)])
         run_i, run_f, wait_i, wait_f, par, clk = map(
             grow, (run_i, run_f, wait_i, wait_f, par, clk))
 
@@ -133,10 +134,10 @@ def lockstep_advance(params: dict, queues: dict, clocks: jax.Array,
         latency_L=latency_L, admit_order=admit_order, block_n=bn,
         interpret=interpret)
 
-    cut = lambda x: x[:n] if pad else x
+    cut = lambda x: x[..., :n, :] if pad else x
     queues = {
-        "run_i": unfold_channels(cut(run_i), RUN_I_CH),
-        "run_f": unfold_channels(cut(run_f), RUN_F_CH),
+        "run_i": channel_minor(cut(run_i)),
+        "run_f": channel_minor(cut(run_f)),
         "wait_i": queues["wait_i"].at[..., WI_VALID].set(cut(wvalid)),
         "wait_f": queues["wait_f"],
     }

@@ -11,6 +11,7 @@ import functools
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 
 def device_order(n_devices: int = None):
@@ -24,22 +25,11 @@ def device_order(n_devices: int = None):
     return devs[:n_devices] if n_devices else devs
 
 
-def make_mesh_compat(shape, axes):
-    """`jax.make_mesh` with explicit Auto axis_types where the installed jax
-    supports them (>= 0.5); older jax has neither the kwarg nor
-    ``jax.sharding.AxisType`` and defaults to Auto anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips when multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 @functools.lru_cache(maxsize=None)
@@ -50,7 +40,7 @@ def _expert_mesh_cached(n: int):
         # live on its own devices.  jax.make_mesh's device assignment is
         # free to interleave hosts, so we bypass it here.
         return jax.sharding.Mesh(np.asarray(device_order(n)), ("expert",))
-    return make_mesh_compat((n,), ("expert",))
+    return jax.make_mesh((n,), ("expert",), axis_types=(AxisType.Auto,))
 
 
 def make_expert_mesh(n_devices: int = None):
@@ -92,7 +82,8 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = max(1, min(model, n // max(data, 1)))
-    return make_mesh_compat((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 # TPU v5e hardware constants used by the roofline analysis

@@ -21,6 +21,7 @@ import numpy as np
 from repro.configs import get_config, reduce_config
 from repro.env import env as env_lib, profiles, serve_engine
 from repro.env.serve_engine import ExpertServer, Request, calibrate
+from repro.launch import compile_cache
 from repro.models import model as model_lib
 
 DEFAULT_EXPERTS = ["qwen1.5-0.5b", "h2o-danube-3-4b", "starcoder2-15b"]
@@ -126,6 +127,7 @@ def main() -> None:
     p.add_argument("--rate", type=float, default=20.0)
     p.add_argument("--router", default="sqf", choices=["rr", "sqf", "random"])
     args = p.parse_args()
+    compile_cache.configure()
 
     print(f"[serve] building cluster: {args.experts}")
     servers = build_cluster(args.experts)

@@ -48,6 +48,7 @@ import jax
 
 from repro.configs import get_config, reduce_config
 from repro.data.pipeline import DataConfig, SyntheticLM
+from repro.launch import compile_cache
 from repro.launch.mesh import (make_host_mesh, make_production_mesh,
                                make_train_mesh)
 from repro.train.trainer import Trainer, TrainerConfig
@@ -111,7 +112,9 @@ def train_router_main(args) -> None:
           f"{history[-1]['collect_reward']:.3f}")
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
+    """The launcher's command line (``train_router_main`` reads the
+    ``--router`` options; the rest drive LM training)."""
     p = argparse.ArgumentParser()
     p.add_argument("--router", action="store_true",
                    help="train the QoS DRL router instead of an LM")
@@ -155,7 +158,12 @@ def main() -> None:
     p.add_argument("--data-parallel", type=int, default=1)
     p.add_argument("--model-parallel", type=int, default=1)
     p.add_argument("--production-mesh", action="store_true")
-    args = p.parse_args()
+    return p
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    compile_cache.configure()
 
     if args.router:
         train_router_main(args)

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 from repro.distributed.api import current_policy
 from repro.models import layers
 
@@ -167,7 +165,7 @@ def _moe_sharded(params: dict, x: jax.Array, cfg, mesh) -> Tuple[jax.Array, jax.
         return out.astype(x_l.dtype), aux
 
     dspec = data_axes if len(data_axes) > 1 else (data_axes[0] if data_axes else None)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), P("model", None, None), P("model", None, None),
                   P("model", None, None), P(dspec, None)),

@@ -30,8 +30,9 @@ def test_moe_sharded_matches_local():
         from repro.configs import get_config, reduce_config
         from repro.models import moe
         from repro.distributed.api import MeshPolicy, use_mesh_policy
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg = reduce_config(get_config("dbrx-132b"), capacity_factor=8.0)
         key = jax.random.PRNGKey(0)
         params = moe.init_moe(key, cfg, jnp.float32)
@@ -59,8 +60,9 @@ def test_train_step_on_mesh_runs():
         from repro.train import optimizer as opt_lib
         from repro.models import model
         from repro.distributed import sharding
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((4, 2), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg = reduce_config(get_config("qwen1.5-0.5b"),
                             d_model=64, n_heads=4, n_kv_heads=2, d_head=16)
         opt = opt_lib.make_optimizer("adamw", total_steps=4)
@@ -86,8 +88,9 @@ def test_collectives_multidevice():
     run_py("""
         import jax, jax.numpy as jnp
         from repro.distributed import collectives
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((8,), ("data",))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(AxisType.Auto,) * 1)
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
         want = jnp.sum(x, axis=0)
         got = collectives.ring_allreduce(x, mesh, "data")
@@ -174,7 +177,6 @@ def test_sharded_replay_multidevice():
         import functools
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro import compat
         from repro.core import replay
         from repro.distributed import sharding
         from repro.launch.mesh import make_train_mesh
@@ -199,7 +201,7 @@ def test_sharded_replay_multidevice():
             return replay.shard_add_batch(
                 buf, o, a, r, d, no,
                 shard_idx=jax.lax.axis_index("expert"), n_shards=S)
-        ins = compat.shard_map(
+        ins = jax.shard_map(
             ins_body, mesh=mesh, in_specs=(specs, P(), P(), P(), P(), P()),
             out_specs=specs, check_vma=False)
         for seed in range(11):   # 11*8 = 88 rows -> wraps the ring
@@ -218,7 +220,7 @@ def test_sharded_replay_multidevice():
                 buf, key, 16, shard_idx=jax.lax.axis_index("expert"),
                 n_shards=S)
             return jax.lax.psum(c, "expert")
-        smp = compat.shard_map(smp_body, mesh=mesh, in_specs=(specs, P()),
+        smp = jax.shard_map(smp_body, mesh=mesh, in_specs=(specs, P()),
                                out_specs=P(), check_vma=False)
         key = jax.random.PRNGKey(5)
         want = replay.sample(ref, key, 16)

@@ -109,14 +109,11 @@ def test_examples_run_heterogeneous_fleet():
 def test_launch_train_router_on_scenarios():
     """launch.train --router --scenario <name> end to end (tiny budgets)
     for three registry scenarios — the ISSUE-5 acceptance criterion."""
-    import argparse
-
     from repro.launch import train as train_launch
 
     for name in ("flash_crowd", "rolling_outage", "memory_pressure"):
-        args = argparse.Namespace(
-            router=True, router_mesh=False, obs_fmt="padded",
-            ragged_caps=False, scenario=name, iters=2)
+        args = train_launch.build_parser().parse_args(
+            ["--router", "--scenario", name, "--iters", "2"])
         train_launch.train_router_main(args)
 
 

@@ -1,0 +1,83 @@
+"""The lockstep kernel compiles for a TPU v5e chip.
+
+Interpret mode runs the kernel as plain XLA and cannot see what the TPU
+compiler refuses (reshapes across the lane dim, i1 loop carries, blocks
+that overflow VMEM).  These cases compile the real kernel at the engine's
+packed widths for a described ``v5e:2x2`` topology: nothing runs, no chip
+is needed, only the TPU compiler that ships with libtpu.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may hold libtpu, and every test worker imports this
+file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.env.engine_layout import (RUN_F_CH, RUN_I_CH, WAIT_F_CH,
+                                     WAIT_I_CH)
+from repro.kernels.lockstep_advance import ops
+
+R = W = 5  # the paper's run/wait queue widths (EnvConfig defaults)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no libtpu in this installation
+            jax.config.update("jax_enable_compilation_cache", was)
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("n,admit_order", [
+    (6, "fifo"), (1024, "fifo"), (4096, "fifo"), (1024, "qos_aged")])
+def test_lockstep_kernel_compiles_for_v5e(one_chip, n, admit_order):
+    """Ragged caps, an availability mask and an admit_min floor ride in
+    the parameter pack; block_n is the auto-tuned default, so this also
+    checks that the default fits the chip's scoped VMEM."""
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    f32 = jnp.float32
+    params = {"k1": sds((n,), f32), "k2": sds((n,), f32),
+              "mem_capacity": sds((n,), f32),
+              "mem_per_token": sds((n,), f32),
+              "run_cap": sds((n,), jnp.int32),
+              "wait_cap": sds((n,), jnp.int32),
+              "up": sds((n,), jnp.bool_),
+              "admit_min": sds((n,), f32)}
+    queues = {"run_i": sds((n, R, RUN_I_CH), jnp.int32),
+              "run_f": sds((n, R, RUN_F_CH), f32),
+              "wait_i": sds((n, W, WAIT_I_CH), jnp.int32),
+              "wait_f": sds((n, W, WAIT_F_CH), f32)}
+
+    def step(params, queues, clocks, t_next):
+        return ops.lockstep_advance(params, queues, clocks, t_next,
+                                    latency_L=0.030,
+                                    admit_order=admit_order,
+                                    interpret=False)
+
+    compiled = jax.jit(step).lower(params, queues, sds((n,), f32),
+                                   sds((), f32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
