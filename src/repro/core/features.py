@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import scenarios
+from repro.core import spans
 from repro.env import engine_layout as layout
 
 REQ_FEATS = 7
@@ -83,6 +84,7 @@ def fixed_order_mean(x: jax.Array) -> jax.Array:
     return fixed_order_sum(x) / x.shape[-1]
 
 
+@jax.named_scope(spans.OBS)
 def build_obs(cfg, pool, state: dict, *, fmt: str = "padded") -> dict:
     """Returns the heterogeneous-graph observation in the given layout."""
     if fmt not in ("padded", "segments"):

@@ -37,6 +37,8 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import spans
+
 
 def init(capacity: int, obs_example: Dict) -> dict:
     zeros_like_batched = lambda x: jnp.zeros((capacity,) + x.shape, x.dtype)
@@ -52,6 +54,7 @@ def init(capacity: int, obs_example: Dict) -> dict:
     }
 
 
+@jax.named_scope(spans.INSERT)
 def add_batch(buf: dict, obs, action, reward, discount, next_obs) -> dict:
     """Insert a batch of transitions (ring buffer)."""
     cap = buf["capacity"]
@@ -70,6 +73,7 @@ def add_batch(buf: dict, obs, action, reward, discount, next_obs) -> dict:
     }
 
 
+@jax.named_scope(spans.SAMPLE)
 def sample(buf: dict, key, batch_size: int) -> Dict:
     idx = jax.random.randint(key, (batch_size,), 0,
                              jnp.maximum(buf["size"], 1))
@@ -101,6 +105,7 @@ def _owned_rows(buf: dict, idx: jax.Array, shard_idx,
     return hit, local, cap_local
 
 
+@jax.named_scope(spans.INSERT)
 def shard_add_batch(buf: dict, obs, action, reward, discount, next_obs, *,
                     shard_idx, n_shards: int) -> dict:
     """Per-shard ring-buffer insert: scatter the transitions whose global
@@ -127,6 +132,7 @@ def shard_add_batch(buf: dict, obs, action, reward, discount, next_obs, *,
     }
 
 
+@jax.named_scope(spans.SAMPLE)
 def shard_sample_local(buf: dict, key, batch_size: int, *,
                        shard_idx, n_shards: int) -> Dict:
     """This shard's additive contribution to a global ``sample``: owned

@@ -13,7 +13,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import features, han as han_lib
+from repro.core import features, han as han_lib, spans
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,6 +125,7 @@ def actor_logits(params, cfg: SACConfig, obs) -> jax.Array:
     return _mlp(params["actor"], z)
 
 
+@jax.named_scope(spans.POLICY)
 def act(params, cfg: SACConfig, obs, key, *, greedy: bool = False) -> jax.Array:
     logits = actor_logits(params, cfg, obs)
     if greedy:

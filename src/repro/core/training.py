@@ -41,7 +41,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import features, replay, sac as sac_lib
+from repro.core import features, replay, sac as sac_lib, spans
 from repro.env import env as env_lib
 from repro.train import optimizer as opt_lib
 
@@ -198,9 +198,10 @@ def make_iteration(env_cfg: env_lib.EnvConfig, sac_cfg: sac_lib.SACConfig,
                             jnp.ones_like(rew), next_obs)
             return (env_states2, next_obs, buf, key), jnp.mean(rew)
 
-        (env_states, _, buf, key), rews = jax.lax.scan(
-            collect, (env_states, gather(obs_of(env_states)), buf, key), None,
-            length=tc.collect_steps)
+        with jax.named_scope(spans.COLLECT):
+            (env_states, _, buf, key), rews = jax.lax.scan(
+                collect, (env_states, gather(obs_of(env_states)), buf, key),
+                None, length=tc.collect_steps)
 
         def update(carry, _):
             params, opt_state, key = carry
@@ -234,8 +235,9 @@ def make_iteration(env_cfg: env_lib.EnvConfig, sac_cfg: sac_lib.SACConfig,
                      "entropy": jnp.float32(0), "q_mean": jnp.float32(0)}
             return params, opt_state, key, dummy
 
-        params, opt_state, key, aux = jax.lax.cond(
-            do_update, run_updates, skip_updates, (params, opt_state, key))
+        with jax.named_scope(spans.UPDATE):
+            params, opt_state, key, aux = jax.lax.cond(
+                do_update, run_updates, skip_updates, (params, opt_state, key))
         aux["collect_reward"] = jnp.mean(rews)
         return params, opt_state, env_states, buf, key, aux
 

@@ -153,6 +153,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import spans
 from repro.env.engine_layout import (  # noqa: F401  (re-exported layout API)
     RI_VALID, RI_P, RI_D_TRUE, RI_D_CUR, RI_RETRY, RUN_I_CH,
     RF_SCORE, RF_PRED_S, RF_PRED_D, RF_T_ARRIVE, RF_T_ADMIT, RUN_F_CH,
@@ -331,6 +332,7 @@ def advance_shard(params: dict, latency_L: float, queues: dict,
     def cond(c):
         return jnp.any(c[5])  # carried active mask
 
+    @jax.named_scope(spans.TRIP)
     def body(c):
         run_i, run_f, wvalidb, clocks, acc, active = c
         validb = run_i[..., RI_VALID] > 0                  # (N, R)
@@ -497,21 +499,23 @@ def advance_all(pool: ExpertPool, latency_L: float, queues: dict,
         # fall through to the last ordering
         raise ValueError(f"unknown admit_order {admit_order!r}; "
                          f"expected one of {ADMIT_ORDERS}")
-    params = pool_params(pool, run_caps, wait_caps, up, k_scale, admit_min)
-    if backend == "xla":
-        return advance_shard(params, latency_L, queues, clocks, t_next,
-                             admit_order=admit_order)
-    if backend == "pallas":
-        from repro.kernels.lockstep_advance.ops import lockstep_advance
-        return lockstep_advance(params, queues, clocks, t_next,
-                                latency_L=float(latency_L),
-                                admit_order=admit_order, block_n=block_n)
-    if backend == "shard_map":
-        if mesh is None:
-            from repro.launch.mesh import make_expert_mesh
-            mesh = make_expert_mesh()
-        return _advance_shard_map(params, latency_L, queues, clocks, t_next,
-                                  admit_order=admit_order, mesh=mesh,
-                                  shard_body=shard_body, block_n=block_n)
+    with jax.named_scope(spans.ADVANCE):
+        params = pool_params(pool, run_caps, wait_caps, up, k_scale, admit_min)
+        if backend == "xla":
+            return advance_shard(params, latency_L, queues, clocks, t_next,
+                                 admit_order=admit_order)
+        if backend == "pallas":
+            from repro.kernels.lockstep_advance.ops import lockstep_advance
+            return lockstep_advance(params, queues, clocks, t_next,
+                                    latency_L=float(latency_L),
+                                    admit_order=admit_order, block_n=block_n)
+        if backend == "shard_map":
+            if mesh is None:
+                from repro.launch.mesh import make_expert_mesh
+                mesh = make_expert_mesh()
+            return _advance_shard_map(
+                params, latency_L, queues, clocks, t_next,
+                admit_order=admit_order, mesh=mesh, shard_body=shard_body,
+                block_n=block_n)
     raise ValueError(f"unknown engine backend {backend!r}; "
                      f"expected one of {BACKENDS}")

@@ -269,4 +269,6 @@ def lockstep_advance_call(run_i, run_f, wait_i, wait_f, par, clocks, t_next,
             jax.ShapeDtypeStruct((n, N_ACC), jnp.float32),
         ],
         interpret=interpret,
+        # a stable name: trace reductions find the kernel by it
+        name="lockstep_advance",
     )(t_next, run_i, run_f, wait_i, wait_f, par, clocks)

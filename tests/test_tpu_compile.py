@@ -81,3 +81,27 @@ def test_lockstep_kernel_compiles_for_v5e(one_chip, n, admit_order):
     compiled = jax.jit(step).lower(params, queues, sds((n,), f32),
                                    sds((), f32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_lockstep_kernel_carries_its_name(one_chip):
+    """The kernel is named ``lockstep_advance`` for the chip, whatever its
+    Python function is called, so that trace reductions find it."""
+    n = 6
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    f32 = jnp.float32
+    params = {k: sds((n,), f32) for k in ("k1", "k2", "mem_capacity",
+                                          "mem_per_token")}
+    queues = {"run_i": sds((n, R, RUN_I_CH), jnp.int32),
+              "run_f": sds((n, R, RUN_F_CH), f32),
+              "wait_i": sds((n, W, WAIT_I_CH), jnp.int32),
+              "wait_f": sds((n, W, WAIT_F_CH), f32)}
+
+    def step(params, queues, clocks, t_next):
+        return ops.lockstep_advance(params, queues, clocks, t_next,
+                                    latency_L=0.030, admit_order="fifo",
+                                    interpret=False)
+
+    lowered = jax.jit(step).lower(params, queues, sds((n,), f32),
+                                  sds((), f32))
+    assert 'kernel_name = "lockstep_advance"' in lowered.as_text()
