@@ -5,8 +5,8 @@ A scope is HLO metadata only: it lands in each instruction's
 adds no operation, so the program carries its scopes always and costs
 nothing for them.  A profiler trace, joined with those op names, splits
 device time by layer (``bench/scopes.py``).  Scopes nest: ``TRIP`` sits
-inside ``ADVANCE``, ``INSERT`` inside ``COLLECT``, ``SAMPLE`` inside
-``UPDATE``.
+inside ``ADVANCE``, ``INSERT`` inside ``COLLECT``, ``WRAP`` inside
+``INSERT``, ``SAMPLE`` inside ``UPDATE``.
 """
 from __future__ import annotations
 
@@ -16,7 +16,8 @@ ADVANCE = "engine.advance"    # engine.advance_all, whichever backend runs
 TRIP = "engine.trip"          # one trip of advance_shard's lockstep loop
 COLLECT = "train.collect"     # make_iteration's collect scan
 INSERT = "replay.insert"      # replay.add_batch and its sharded twin
+WRAP = "replay.wrap"          # add_batch's write of a block that wraps the ring
 SAMPLE = "replay.sample"      # replay.sample and its sharded twin
 UPDATE = "sac.update"         # make_iteration's update: sample, loss, Adam, Polyak
 
-SCOPES = (OBS, POLICY, ADVANCE, TRIP, COLLECT, INSERT, SAMPLE, UPDATE)
+SCOPES = (OBS, POLICY, ADVANCE, TRIP, COLLECT, INSERT, SAMPLE, UPDATE, WRAP)

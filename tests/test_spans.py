@@ -118,5 +118,5 @@ def test_trip_sits_under_advance(compiled):
 
 
 def test_scopes_are_named_once():
-    assert len(set(spans.SCOPES)) == len(spans.SCOPES) == 8
+    assert len(set(spans.SCOPES)) == len(spans.SCOPES) == 9
     assert all(re.fullmatch(r"[a-z]+\.[a-z]+", s) for s in spans.SCOPES)

@@ -11,6 +11,8 @@ process at a time may hold libtpu, and every test worker imports this
 file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -105,3 +107,40 @@ def test_lockstep_kernel_carries_its_name(one_chip):
     lowered = jax.jit(step).lower(params, queues, sds((n,), f32),
                                   sds((), f32))
     assert 'kernel_name = "lockstep_advance"' in lowered.as_text()
+
+
+@pytest.mark.parametrize("capacity_arg", ["traced", "static"])
+def test_replay_insert_writes_in_place_for_v5e(one_chip, capacity_arg):
+    """The donated insert of one collect step of the paper's training (16
+    envs, padded observations of 6 experts) into the 100,000-row buffer
+    keeps the buffer in its parameter layout: no instruction, in either
+    branch of the insert, copies a capacity-sized leaf.  A scatter there
+    relaid out each of the 12 leaves twice (24 such copies)."""
+    from repro.core import replay
+
+    cap, n = 100_000, 16
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    f32 = jnp.float32
+    obs = {"expert": ((6, 9), f32), "run": ((6, R, 7), f32),
+           "wait": ((6, W, 7), f32), "run_mask": ((6, R), jnp.bool_),
+           "wait_mask": ((6, W), jnp.bool_), "arrived": ((7,), f32)}
+    rows = lambda m: {k: sds((m,) + s, dt) for k, (s, dt) in obs.items()}
+    buf = {"obs": rows(cap), "next_obs": rows(cap),
+           "action": sds((cap,), jnp.int32), "reward": sds((cap,), f32),
+           "discount": sds((cap,), f32), "ptr": sds((), jnp.int32),
+           "size": sds((), jnp.int32), "capacity": sds((), jnp.int32)}
+    if capacity_arg == "static":
+        del buf["capacity"]
+
+    def insert(buf, *batch):
+        if capacity_arg == "static":
+            buf = dict(buf, capacity=cap)
+        return replay.add_batch(buf, *batch)
+
+    text = jax.jit(insert, donate_argnums=0).lower(
+        buf, rows(n), sds((n,), jnp.int32), sds((n,), f32), sds((n,), f32),
+        rows(n)).compile().as_text()
+    copies = re.findall(rf"= \w+\[{cap}[,\]]\S* copy\(", text)
+    assert not copies, copies
+    assert "conditional" in text
