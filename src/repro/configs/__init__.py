@@ -22,6 +22,7 @@ _ARCH_MODULES = {
     "chameleon-34b": "chameleon_34b",
     "rwkv6-7b": "rwkv6_7b",
     "recurrentgemma-2b": "recurrentgemma_2b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

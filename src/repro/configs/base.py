@@ -31,16 +31,36 @@ class ModelConfig:
     vocab: int
 
     # --- attention ---
-    attention: str = "full"  # full | swa
+    attention: str = "full"  # full | swa | mla
     window: int = 0  # sliding window size when attention == "swa"
     rope_theta: float = 10_000.0
     qkv_bias: bool = False
+
+    # --- latent attention (attention == "mla", repro.models.mla) ---
+    kv_lora_rank: int = 0  # width of the cached latent
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0  # rope dims, one key shared by all heads
+    v_head_dim: int = 0
+    # YaRN scaling of the rope dims; yarn_factor 0 means plain RoPE
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- MoE ---
     n_experts: int = 0
     top_k: int = 0
     n_dense_layers: int = 0  # leading dense layers before MoE layers
     capacity_factor: float = 1.25
+    d_ff_expert: int = 0  # width of a routed or shared expert (0: d_ff)
+    n_shared_experts: int = 0  # experts every token passes, one MLP
+    norm_topk_prob: bool = True  # renormalise the top-k gates to sum 1
+    routed_scaling_factor: float = 1.0
+    # ids of the routed experts this device holds (DeepSeek-V2 kind,
+    # ``moe.held_moe_block``); empty: all of them
+    held_experts: Tuple[int, ...] = ()
     moe_psum_dtype: str = "float32"  # bf16 halves EP combine wire bytes
 
     # --- SSM (rwkv6) ---
@@ -102,14 +122,30 @@ class ModelConfig:
     def has_decoder(self) -> bool:
         return True  # every assigned arch has an autoregressive decoder
 
+    @property
+    def expert_width(self) -> int:
+        return self.d_ff_expert or self.d_ff
+
+    @property
+    def held(self) -> Tuple[int, ...]:
+        """Ids of the routed experts this device holds."""
+        return self.held_experts or tuple(range(self.n_experts))
+
     def n_params(self) -> int:
         """Analytic parameter count (embedding unpadded)."""
         d, f, v = self.d_model, self.d_ff, self.vocab
         h, kv, dh = self.n_heads, self.n_kv_heads, self.d_head
         attn = d * h * dh + 2 * d * kv * dh + h * dh * d
+        if self.attention == "mla":
+            r, nope = self.kv_lora_rank, self.qk_nope_head_dim
+            rope, vd = self.qk_rope_head_dim, self.v_head_dim
+            attn = (d * h * (nope + rope) + d * (r + rope) + r
+                    + r * h * (nope + vd) + h * vd * d)
         if self.family == "moe":
             moe_layers = self.n_layers - self.n_dense_layers
-            ffn_moe = self.n_experts * 3 * d * f + d * self.n_experts
+            fe = self.expert_width
+            ffn_moe = ((self.n_experts + self.n_shared_experts) * 3 * d * fe
+                       + d * self.n_experts)
             ffn_dense = 3 * d * f
             ffn_total = moe_layers * ffn_moe + self.n_dense_layers * ffn_dense
             per_layer_rest = attn + 2 * d
@@ -141,9 +177,9 @@ class ModelConfig:
         """Params touched per token (MoE: top_k of n_experts)."""
         if self.family != "moe":
             return self.n_params()
-        d, f = self.d_model, self.d_ff
         moe_layers = self.n_layers - self.n_dense_layers
-        inactive = moe_layers * (self.n_experts - self.top_k) * 3 * d * f
+        inactive = (moe_layers * (self.n_experts - self.top_k) * 3
+                    * self.d_model * self.expert_width)
         return self.n_params() - inactive
 
 
@@ -193,6 +229,11 @@ def reduce_config(cfg: ModelConfig, **overrides) -> ModelConfig:
     )
     if cfg.family == "moe":
         small.update(n_experts=4, top_k=2, n_dense_layers=min(cfg.n_dense_layers, 1))
+    if cfg.attention == "mla":
+        small.update(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                     v_head_dim=16, d_head=24, d_ff_expert=32,
+                     n_shared_experts=min(cfg.n_shared_experts, 1),
+                     held_experts=())
     if cfg.family == "hybrid":
         small.update(rnn_width=64, block_pattern=cfg.block_pattern, n_layers=3)
     if cfg.family == "ssm":

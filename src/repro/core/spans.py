@@ -6,7 +6,8 @@ adds no operation, so the program carries its scopes always and costs
 nothing for them.  A profiler trace, joined with those op names, splits
 device time by layer (``bench/scopes.py``).  Scopes nest: ``TRIP`` sits
 inside ``ADVANCE``, ``INSERT`` inside ``COLLECT``, ``WRAP`` inside
-``INSERT``, ``SAMPLE`` inside ``UPDATE``.
+``INSERT``, ``SAMPLE`` inside ``UPDATE``; on the serving path ``MLA``,
+``ROUTED`` and ``SHARED`` sit inside ``DECODE``.
 """
 from __future__ import annotations
 
@@ -19,5 +20,10 @@ INSERT = "replay.insert"      # replay.add_batch and its sharded twin
 WRAP = "replay.wrap"          # add_batch's write of a block that wraps the ring
 SAMPLE = "replay.sample"      # replay.sample and its sharded twin
 UPDATE = "sac.update"         # make_iteration's update: sample, loss, Adam, Polyak
+DECODE = "serve.decode"       # a served model's decode step over all slots
+MLA = "mla.attn"              # latent attention of one layer
+ROUTED = "moe.routed"         # the held routed experts of one MoE layer
+SHARED = "moe.shared"         # the shared experts of one MoE layer
 
-SCOPES = (OBS, POLICY, ADVANCE, TRIP, COLLECT, INSERT, SAMPLE, UPDATE, WRAP)
+SCOPES = (OBS, POLICY, ADVANCE, TRIP, COLLECT, INSERT, SAMPLE, UPDATE, WRAP,
+          DECODE, MLA, ROUTED, SHARED)

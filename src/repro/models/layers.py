@@ -267,6 +267,12 @@ def dense_init(key, shape, dtype, scale: Optional[float] = None):
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
 
+def scaled_normal(key, shape, dtype, fan_in: int, scale: float = 1.0):
+    """Normal draws in ``dtype`` with std ``scale / sqrt(fan_in)``."""
+    return (jax.random.normal(key, shape, dtype) * (scale / fan_in ** 0.5)
+            ).astype(dtype)
+
+
 def embed_init(key, shape, dtype):
     return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
 

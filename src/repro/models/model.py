@@ -18,10 +18,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models import encdec, rglru, rwkv6, transformer
+from repro.models import encdec, mla, rglru, rwkv6, transformer
 
 
 def _family_mod(cfg: ModelConfig):
+    if cfg.attention == "mla":
+        return mla
     if cfg.family in ("dense", "moe"):
         return transformer
     if cfg.family == "ssm":

@@ -17,6 +17,13 @@ Two execution paths sharing the same math:
 
 Capacity is rounded to a multiple of 128 so buffers stay MXU/shard friendly;
 overflow tokens are dropped exactly like capacity-factor dropping in GShard.
+
+A served expert may not drop tokens.  ``held_moe_block`` is the dropless
+layer of the DeepSeek-V2 kind: it is told the ids of the routed experts
+this device holds, routes every token over all of them, and computes its
+own experts' share of the result with grouped matrix products over the
+tokens sorted by expert, plus the shared experts that every token passes.
+On one device it runs without the exchange that expert parallelism adds.
 """
 from __future__ import annotations
 
@@ -27,6 +34,7 @@ import jax.numpy as jnp
 
 from jax.sharding import PartitionSpec as P
 
+from repro.core import spans
 from repro.distributed.api import current_policy
 from repro.models import layers
 
@@ -42,12 +50,17 @@ def init_moe(key, cfg, dtype) -> dict:
     }
 
 
-def route_topk(logits: jax.Array, top_k: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Returns (gates (T,k) fp32 normalized, expert_ids (T,k) int32, probs)."""
+def route_topk(logits: jax.Array, top_k: int, normalize: bool = True,
+               scale: float = 1.0) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Returns (gates (T,k) fp32, expert_ids (T,k) int32, probs): the top-k
+    softmax scores, renormalised to sum 1 where ``normalize``, times
+    ``scale``."""
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     gates, ids = jax.lax.top_k(probs, top_k)
-    gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
-    return gates, ids.astype(jnp.int32), probs
+    if normalize:
+        gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True),
+                                    1e-9)
+    return gates * scale, ids.astype(jnp.int32), probs
 
 
 def _slot_in_expert(expert_ids_flat: jax.Array, n_experts: int) -> jax.Array:
@@ -183,3 +196,84 @@ def moe_block(params: dict, x: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
             and policy.mesh.shape["model"] > 1:
         return _moe_sharded(params, x, cfg, policy.mesh)
     return _moe_local(params, x, cfg)
+
+
+# ---------------------------------------------------------------------------
+# Dropless layer over the routed experts a device holds, with shared experts
+# ---------------------------------------------------------------------------
+
+
+def init_held_moe(key, cfg, dtype) -> dict:
+    """Gate over all ``cfg.n_experts``, the shared experts as one MLP, and
+    the routed experts whose ids ``cfg.held`` lists.  Expert ``e``'s weights
+    come from ``fold_in(key, e)``, so every share of the experts holds the
+    same weights for the same id."""
+    d, fe, e = cfg.d_model, cfg.expert_width, cfg.n_experts
+    fs = cfg.n_shared_experts * fe
+    k_gate, k_shared, k_routed = jax.random.split(key, 3)
+    ks = jax.random.split(k_shared, 3)
+    down = 1.0 / (2 * cfg.n_layers) ** 0.5
+    nrm = layers.scaled_normal
+
+    def expert(eid):
+        kk = jax.random.split(jax.random.fold_in(k_routed, eid), 3)
+        return {"w_gate": nrm(kk[0], (d, fe), dtype, d),
+                "w_up": nrm(kk[1], (d, fe), dtype, d),
+                "w_down": nrm(kk[2], (fe, d), dtype, fe, down)}
+
+    p = {"gate": nrm(k_gate, (d, e), dtype, d),
+         "routed": jax.vmap(expert)(jnp.asarray(cfg.held, jnp.int32))}
+    if fs:
+        p["shared"] = {"w_gate": nrm(ks[0], (d, fs), dtype, d),
+                       "w_up": nrm(ks[1], (d, fs), dtype, d),
+                       "w_down": nrm(ks[2], (fs, d), dtype, fs, down)}
+    return p
+
+
+def held_expert_ffn(params: dict, x: jax.Array, ids: jax.Array,
+                    gates: jax.Array, held: jax.Array):
+    """The held experts' share of the routed output, dropping no token.
+
+    x (T, d); ids, gates (T, k) over all experts; held (H,) ids.  The T*k
+    assignments are sorted by held expert, those to experts held elsewhere
+    last; each held expert's rows go through its SwiGLU in one grouped
+    product (``lax.ragged_dot``), the rest are left out.  Returns the
+    (T, d) share and the (H,) tokens each held expert took."""
+    T, d = x.shape
+    k = ids.shape[1]
+    n_held = held.shape[0]
+    match = ids.reshape(-1)[:, None] == held[None, :]          # (T*k, H)
+    local = jnp.where(jnp.any(match, -1), jnp.argmax(match, -1), n_held)
+    order = jnp.argsort(local, stable=True)
+    sizes = jnp.sum(match, axis=0, dtype=jnp.int32)
+    tok = order // k
+    rows = x[tok]
+    w = params["routed"]
+    h = jax.lax.ragged_dot(rows, w["w_gate"], sizes)
+    u = jax.lax.ragged_dot(rows, w["w_up"], sizes)
+    y = jax.lax.ragged_dot(jax.nn.silu(h) * u, w["w_down"], sizes)
+    mine = local[order] < n_held
+    g = gates.reshape(-1)[order]
+    y = jnp.where(mine[:, None], y.astype(jnp.float32) * g[:, None], 0.0)
+    out = jnp.zeros((T, d), jnp.float32).at[tok].add(y)
+    return out.astype(x.dtype), sizes
+
+
+def held_moe_block(params: dict, x: jax.Array, cfg):
+    """x (T, d) -> (out (T, d), tokens per held expert (H,)): the shared
+    experts plus the held routed experts' share, gates as DeepSeek-V2's
+    (softmax scores in float32, top-k, renormalised only where
+    ``cfg.norm_topk_prob``, times ``cfg.routed_scaling_factor``)."""
+    logits = jnp.dot(x.astype(jnp.float32),
+                     params["gate"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    gates, ids, _ = route_topk(logits, cfg.top_k, cfg.norm_topk_prob,
+                               cfg.routed_scaling_factor)
+    with jax.named_scope(spans.ROUTED):
+        out, sizes = held_expert_ffn(params, x, ids, gates,
+                                     jnp.asarray(cfg.held, jnp.int32))
+    if "shared" in params:
+        with jax.named_scope(spans.SHARED):
+            s = params["shared"]
+            out = out + layers.swiglu(x, s["w_gate"], s["w_up"], s["w_down"])
+    return out, sizes

@@ -221,12 +221,17 @@ def forward(params: dict, cfg, tokens: jax.Array) -> Tuple[jax.Array, jax.Array]
     return logits, aux
 
 
-def unembed(params, cfg, x):
+def unembed(params, cfg, x, out_dtype=None):
+    """x (B, S, d) -> logits (B, S, Vp), the padded vocabulary masked;
+    ``out_dtype`` float32 accumulates the head's product in float32 and
+    returns it so."""
     x = constrain(x, "batch", None, None)
     if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
+        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"],
+                            preferred_element_type=out_dtype)
     else:
-        logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
+        logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"],
+                            preferred_element_type=out_dtype)
     logits = constrain(logits, "batch", None, "vocab")
     if cfg.vocab_padded != cfg.vocab:
         pad_mask = jnp.arange(cfg.vocab_padded) >= cfg.vocab

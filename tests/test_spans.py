@@ -1,9 +1,11 @@
-"""The router's layers are named in the compiled program.
+"""The router's and the served model's layers are named in the compiled
+program.
 
 Each name of ``spans.SCOPES`` has to reach the ``op_name`` metadata of some
 instruction of the optimized HLO, in the routing chunk (built as the
-benchmark's route entry builds it) and in the training iteration, so that
-a trace joined with those names splits device time by layer.  Under the
+benchmark's route entry builds it), in the training iteration, or in the
+decode step of a served DeepSeek-V2-kind model, so that a trace joined
+with those names splits device time by layer.  Under the
 Pallas backend the lockstep loop runs inside one kernel, so no instruction
 is a trip of it.
 """
@@ -13,8 +15,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.configs import get_config, reduce_config
 from repro.core import features, han, routers, sac, spans, training
-from repro.env import env
+from repro.env import env, serve_engine
+from repro.models import model
 
 N = 3
 
@@ -89,12 +93,30 @@ def train_iteration(env_cfg, pool, sac_cfg):
     return iteration.lower(*shapes, key, step).compile().as_text()
 
 
+def serve_decode():
+    """The decode step of an ``ExpertServer`` over a small latent-attention
+    model with shared and routed experts."""
+    cfg = reduce_config(get_config("deepseek-v2-lite"))
+    _, decode = serve_engine.make_steps(cfg, 32)
+    params = jax.eval_shape(lambda: model.init_params(jax.random.PRNGKey(0),
+                                                      cfg))
+    cache = jax.eval_shape(lambda: model.init_cache(cfg, 2, 32))
+    tokens = jax.ShapeDtypeStruct((2,), jnp.int32)
+    return decode.lower(params, cache, tokens).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def decode_names():
+    return op_names(serve_decode())
+
+
 @pytest.fixture(scope="module", params=["xla", "pallas"])
-def compiled(request):
+def compiled(request, decode_names):
     env_cfg, pool, sac_cfg = configs(request.param)
     return request.param, (op_names(route_chunk(env_cfg, pool, sac_cfg))
                            + op_names(train_iteration(env_cfg, pool,
-                                                      sac_cfg)))
+                                                      sac_cfg))
+                           + decode_names)
 
 
 def test_every_scope_reaches_the_compiled_program(compiled):
@@ -117,6 +139,15 @@ def test_trip_sits_under_advance(compiled):
         assert adv is not None and adv.start() < n.index(spans.TRIP), n
 
 
+def test_serving_scopes_sit_under_decode(decode_names):
+    # whole paths (a reduction's body names its ops relative to the call)
+    for scope in (spans.MLA, spans.ROUTED, spans.SHARED):
+        inside = [n for n in decode_names
+                  if has(scope, n) and n.startswith("jit(")]
+        assert inside, scope
+        assert all(has(spans.DECODE, n) for n in inside), scope
+
+
 def test_scopes_are_named_once():
-    assert len(set(spans.SCOPES)) == len(spans.SCOPES) == 9
+    assert len(set(spans.SCOPES)) == len(spans.SCOPES) == 13
     assert all(re.fullmatch(r"[a-z]+\.[a-z]+", s) for s in spans.SCOPES)

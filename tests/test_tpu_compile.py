@@ -144,3 +144,46 @@ def test_replay_insert_writes_in_place_for_v5e(one_chip, capacity_arg):
     copies = re.findall(rf"= \w+\[{cap}[,\]]\S* copy\(", text)
     assert not copies, copies
     assert "conditional" in text
+
+
+def test_served_decode_writes_the_latent_cache_in_place_for_v5e(one_chip):
+    """The decode step of DeepSeek-V2-Lite as ``dsv2lite.serve`` serves it
+    (published widths, 16 of 64 routed experts held, 48 slots of 2,048
+    positions) fits one chip beside its weights and writes the donated
+    latent cache in place: the cache's bytes alias, and no instruction
+    copies a cache-sized array."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.env import serve_engine
+    from repro.models import model
+
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite"),
+                              held_experts=tuple(range(16)))
+    place = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    params = place(jax.eval_shape(
+        lambda: model.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = place(jax.eval_shape(lambda: model.init_cache(cfg, 48, 2048)))
+    tokens = jax.ShapeDtypeStruct((48,), jnp.int32, sharding=one_chip)
+    _, decode = serve_engine.make_steps(cfg, 2048)
+    # at JAX's default precision, as the benchmark runs it (this suite
+    # sets "highest", which the grouped products' kernel refuses for
+    # bfloat16 operands)
+    with jax.default_matmul_precision("default"):
+        compiled = decode.lower(params, cache, tokens).compile()
+    mem = compiled.memory_analysis()
+    latent = sum(cache[k].size * cache[k].dtype.itemsize
+                 for k in ("c_kv", "k_pe"))
+    assert latent <= mem.alias_size_in_bytes < latent + 2**20
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
+    # what produces a whole cache leaf is each layer's scatter of its new
+    # entries into the donated buffer (a kCustom fusion), never a copy or
+    # an elementwise pass over it
+    whole = [line for line in compiled.as_text().splitlines()
+             if re.search(r"= bf16\[27,48,2048,\d+\]\S* \w", line)]
+    assert whole
+    bad = [w for w in whole if " copy(" in w or "kind=kLoop" in w]
+    assert not bad, bad
